@@ -1,0 +1,91 @@
+"""Service mix: what each created service asks of the scheduler.
+
+The configuration lists its service kinds (constraints and spread
+preferences) and the reservation of every replica (scheduler_perf's
+pod-default: 0.1 core, 500 MiB). No host-published ports. Sizes follow a
+Zipf law given by the traffic file (s = 1.2 over 1..1000 replicas, mean
+about 72).
+
+One block of services holds the Zipf quantiles at (i + 0.5) / block as
+sizes, laid out in a low-discrepancy order (position p takes the size whose
+rank is that of frac(p * golden ratio)), so that any run of consecutive
+services, such as those a window reaches, holds nearly the same mix of small
+and large ones; position p has kind p mod the number of kinds. The stream
+repeats the block from an offset drawn from the seed: every seed offers the
+same (size, kind) pairs in another order.
+
+Specs are plain dicts; `to_spec()` builds the program's ServiceSpec.
+"""
+from __future__ import annotations
+
+import numpy as np
+
+MILLICORE = 1_000_000              # nano-cpus in a thousandth of a core
+MIB = 1 << 20
+GOLDEN = (5 ** 0.5 - 1) / 2
+
+
+def zipf_quantiles(n: int, s: float, lo: int, hi: int) -> np.ndarray:
+    """Sizes at the quantiles (i + 0.5) / n of Zipf(s) on [lo, hi]."""
+    k = np.arange(lo, hi + 1, dtype=np.float64)
+    cdf = np.cumsum(k ** -s)
+    cdf /= cdf[-1]
+    u = (np.arange(n) + 0.5) / n
+    return (lo + np.searchsorted(cdf, u)).astype(np.int64)
+
+
+class ServiceStream:
+    """Endless seeded stream of plain service specs."""
+
+    def __init__(self, cfg: dict, sizes: dict, seed: int, block: int,
+                 prefix: str):
+        self._kind_list = cfg["service_kinds"]
+        self._cpu = int(cfg["task_cpu_millicores"]) * MILLICORE
+        self._mem = int(cfg["task_memory_mib"]) * MIB
+        ranked = np.sort(zipf_quantiles(block, sizes["zipf_s"], sizes["min"],
+                                        sizes["max"]))
+        weyl = (np.arange(block) * GOLDEN) % 1.0
+        self._sizes = ranked[np.argsort(np.argsort(weyl))].tolist()
+        self._pos = int(np.random.default_rng(seed).integers(block))
+        self._prefix = prefix
+        self.issued = 0
+
+    def next(self) -> dict:
+        p = self._pos % len(self._sizes)
+        self._pos += 1
+        k = self._kind_list[p % len(self._kind_list)]
+        spec = {
+            "name": f"{self._prefix}{self.issued:06d}",
+            "kind": k["name"],
+            "replicas": int(self._sizes[p]),
+            "cpu": self._cpu,
+            "mem": self._mem,
+            "constraints": [list(c) for c in k["constraints"]],
+            "prefs": list(k["prefs"]),
+        }
+        self.issued += 1
+        return spec
+
+
+def to_spec(plain: dict):
+    """The program's ServiceSpec for a plain spec."""
+    from swarmkit_tpu.api.specs import (
+        Annotations,
+        ContainerSpec,
+        Placement,
+        PlacementPreference,
+        ServiceSpec,
+        TaskSpec,
+    )
+
+    task = TaskSpec(runtime=ContainerSpec(image="busybox", command=["true"]))
+    res = task.resources.reservations
+    res.nano_cpus = plain["cpu"]
+    res.memory_bytes = plain["mem"]
+    task.placement = Placement(
+        constraints=[f"node.labels.{k} {op} {v}"
+                     for k, op, v in plain["constraints"]],
+        preferences=[PlacementPreference(spread_descriptor=f"node.labels.{p}")
+                     for p in plain["prefs"]])
+    return ServiceSpec(annotations=Annotations(name=plain["name"]),
+                       task=task, replicas=plain["replicas"])
