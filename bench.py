@@ -1789,7 +1789,7 @@ def bench_trace_plane(np):
         # THE acceptance: tracing off allocates nothing on the hot path
         "disarmed_span_allocs": disarmed_allocs,
         "stage_breakdown": breakdown,
-        "spans_recorded": rec.spans_started,
+        "spans_recorded": rec.spans_recorded,
         "parity": disarmed_allocs == 0 and bool(breakdown),
     }
 

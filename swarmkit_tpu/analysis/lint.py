@@ -197,7 +197,7 @@ class SpanInLoopRule(Rule):
     placed task in the walk)."""
 
     name = "span-in-loop"
-    invariant = ("no trace.span/start/rec/event, failpoints.fp*, "
+    invariant = ("no trace.span/start/rec/annotate, failpoints.fp*, "
                  "lifecycle.record*, or telemetry snapshot-assembly "
                  "call inside a for/while body in the audited hot "
                  "modules unless under an `if trace.enabled()` / "
@@ -225,7 +225,7 @@ class SpanInLoopRule(Rule):
         "swarmkit_tpu/logbroker/sharded.py",
         "swarmkit_tpu/watchapi/watch.py",
     )
-    TRACE_CALLS = frozenset({"span", "start", "rec", "event", "wrap"})
+    TRACE_CALLS = frozenset({"span", "start", "rec", "annotate", "wrap"})
     FP_CALLS = frozenset({"fp", "fp_value", "fp_transform"})
     LIFECYCLE_CALLS = frozenset({"record", "record_batch", "record_pairs"})
     # telemetry snapshot assembly (ISSUE 15): the heartbeat loop builds

@@ -452,7 +452,7 @@ class DebugServer:
         if parsed.path.rstrip("/").endswith("/recent"):
             r = trace.recorder()
             return {"armed": r is not None,
-                    "spans": r.spans_started if r is not None else 0,
+                    "spans": r.spans_recorded if r is not None else 0,
                     "traces": r.trees() if r is not None else []}
         q = parse_qs(parsed.query)
         try:
@@ -481,7 +481,7 @@ class DebugServer:
                 if temporary and trace.recorder() is r:
                     trace.disarm()
         return {"armed": not temporary, "window_s": seconds,
-                "spans": r.spans_started, "traces": trees}
+                "spans": r.spans_recorded, "traces": trees}
 
     def _slo(self, path: str) -> dict:
         """/debug/slo: startup percentiles (exact recorder samples AND

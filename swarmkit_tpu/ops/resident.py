@@ -47,6 +47,7 @@ from ..scheduler.encode import (
     IncrementalEncoder,
     _bucket,
 )
+from ..utils import trace
 from . import placement
 
 # node-state arrays carried on device, in the order _resident_tick takes
@@ -262,6 +263,9 @@ class ResidentPlacement:
         self._gsrc = None           # per-slot SOURCE object (identity gate)
         self._gdims = None          # padded dims + N the cache was built at
         self.uploads_h2d_bytes = 0  # delta + group-table wire bytes shipped
+        # compile keys of the tick and counts-pull programs dispatched so
+        # far: a key not in here is a shape this process may compile
+        self._shapes_seen: set = set()
 
     # ------------------------------------------------------------ internals
     def _signature(self, p: EncodedProblem) -> tuple:
@@ -607,9 +611,31 @@ class ResidentPlacement:
         if k < G * N and sparse_bytes < dense_bytes:
             dev = _sparse_counts(counts_dev, G, N, k)
             shape = (G, N)
+            pull = ("sparse", G, N, k)
         else:
             dev = _slice_counts(counts_dev, G, N)
             shape = None
+            pull = ("slice", G, N)
+        # the static shapes of the two programs just launched, kept even
+        # disarmed so a traced window knows what warm-up already ran
+        tick_shape = (gp, np_b, kp, plp, pvp, rp, sp, lp, vp,
+                      deltas[0].shape[0])
+        tick_flags = (use_penalty, use_extra, use_voltopo, has_deltas,
+                      compact, strategy)
+        seen = self._shapes_seen
+        new_tick = (tick_shape, tick_flags) not in seen
+        new_pull = pull not in seen
+        if new_tick or new_pull:
+            seen.add((tick_shape, tick_flags))
+            seen.add(pull)
+        if trace.enabled():
+            # onto the caller's tick.dispatch span: which shape ran, and
+            # whether it is the first of its kind (a likely compile)
+            trace.annotate(tick_shape=tick_shape, tick_flags=tick_flags,
+                           pull=pull)
+            if new_tick or new_pull:
+                trace.annotate(new_shape=True, new_tick=new_tick,
+                               new_pull=new_pull)
         try:
             arrs = dev if isinstance(dev, tuple) else (dev,)
             for a in arrs:

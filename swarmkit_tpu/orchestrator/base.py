@@ -11,6 +11,7 @@ import threading
 
 from ..store.memory import MemoryStore
 from ..store.watch import ChannelClosed
+from ..utils import trace
 from ..utils.leadership import leadership_lost
 
 log = logging.getLogger("swarmkit_tpu.orchestrator")
@@ -88,6 +89,12 @@ class EventLoopComponent:
                     continue
                 except ChannelClosed:
                     return
+                # one span per drained burst (armed only): its handle
+                # calls and flush_events; the proposals they make parent
+                # to it, so each names the component that made it
+                sp = None
+                if trace.enabled():
+                    sp = trace.span("loop." + self.name)
                 closed = False
                 drained = 1
                 while True:
@@ -120,6 +127,8 @@ class EventLoopComponent:
                         log.info("%s: leadership lost; stopping", self.name)
                         return
                     log.exception("%s: flush pass failed", self.name)
+                if sp is not None:
+                    sp.end(events=drained)
                 if closed:
                     return
         finally:

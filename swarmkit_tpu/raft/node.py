@@ -526,27 +526,37 @@ class RaftNode:
         (5) release the buffered outgoing messages to the transport."""
         self.ready_flushes += 1
         # ready-loop trace guard: ONE module-global truthiness test when
-        # disarmed (the bench/test acceptance); per-entry work below only
-        # happens for entries that carry a trace ctx
-        traced = trace.enabled()
+        # disarmed (the bench/test acceptance)
+        if not trace.enabled():
+            self._flush_ready_stages(None)
+            return
+        # one raft.ready span per flush, parented to the batch's first
+        # traced entry so the flush joins that proposal's causal trace
+        entries = self._ready_entries
+        with trace.span("raft.ready",
+                        parent=next((e.trace for e in entries
+                                     if e.trace is not None), None),
+                        node=self.id, entries=len(entries)) as sp:
+            self._flush_ready_stages(sp)
+
+    def _flush_ready_stages(self, sp):
+        """_flush_ready's stages; sp is its raft.ready span, None when
+        tracing is disarmed."""
         if self._ready_entries:
             if self.storage is not None:
-                if traced:
+                if sp is not None:
                     _t0 = time.perf_counter()
-                    _tctx = next((e.trace for e in self._ready_entries
-                                  if e.trace is not None), None)
                     _n = len(self._ready_entries)
                 try:
                     self.storage.append_entries(self._ready_entries)
                 except OSError as exc:
                     self._on_append_failure(exc)
                     return
-                if traced:
+                if sp is not None:
                     # one span per GROUP append (one WAL write + fsync),
-                    # parented to the first traced entry so the fsync
-                    # joins the proposal's causal trace; never per-entry
+                    # never per-entry
                     trace.rec("raft.wal_fsync",
-                              time.perf_counter() - _t0, parent=_tctx,
+                              time.perf_counter() - _t0, parent=sp,
                               node=self.id, entries=_n)
                 if self.storage_degraded:
                     # the disk took a durable batch again: leave
@@ -1283,9 +1293,6 @@ class RaftNode:
         e = Entry(term=self.term, index=self._last_index() + 1,
                   kind=ENTRY_NORMAL, data=data, request_id=request_id,
                   trace=trace_ctx)
-        if trace_ctx is not None and trace.enabled():
-            trace.event("raft.stage", parent=trace_ctx,
-                        node=self.id, index=e.index)
         self._append_local(e)
         self._mark_broadcast()
         # the batch flush persists (one fsync for ALL proposals in the
@@ -1472,12 +1479,10 @@ class RaftNode:
             self.commits_applied += 1
             _t0 = None
             if traced and e.trace is not None:
-                # commit event + apply span join the proposal's trace —
-                # on the leader AND on followers (the ctx rode the
-                # replicated entry), which is what makes the causal
-                # propose→fsync→commit→apply chain cross node boundaries
-                trace.event("raft.commit", parent=e.trace,
-                            node=self.id, index=e.index)
+                # the apply span joins the proposal's trace — on the
+                # leader AND on followers (the ctx rode the replicated
+                # entry), which is what makes the causal
+                # propose→fsync→apply chain cross node boundaries
                 _t0 = time.perf_counter()
             if e.kind == ENTRY_CONF_CHANGE:
                 self._apply_conf_change(e)

@@ -127,11 +127,15 @@ class RaftProposer:
         with self._lock:
             self._pending[req_id] = commit_cb
 
-        # trace plane: the proposal's root span — submit→commit-resolve.
+        # trace plane: the proposal's span — submit→commit-resolve, under
+        # the caller's open span (the component or stage that proposes).
         # Its ctx rides the staged Entry (and therefore replication and
-        # the WAL), so every replica's fsync/commit/apply spans join this
-        # trace. None when disarmed: zero allocation on the propose path.
-        sp = trace.start("raft.propose")
+        # the WAL), so every replica's fsync/apply spans join this trace.
+        # Armed only: zero allocation on the disarmed propose path.
+        actions = list(actions)
+        sp = None
+        if trace.enabled():
+            sp = trace.start("raft.propose", actions=len(actions))
 
         def on_result(ok: bool, err: str):
             if not ok:
@@ -141,7 +145,7 @@ class RaftProposer:
                 sp.end(ok=ok)
             handle._resolve(ok, err)
 
-        self.node.propose(list(actions), req_id, on_result,
+        self.node.propose(actions, req_id, on_result,
                           trace_ctx=sp.ctx() if sp is not None else None)
         return handle
 

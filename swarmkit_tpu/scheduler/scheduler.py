@@ -473,6 +473,12 @@ class Scheduler:
                     return
                 now = time.monotonic()
                 if ev is not None:
+                    # one span per burst handled between ticks (armed
+                    # only); files as tick_stage_seconds{stage=drain}
+                    sp = None
+                    if trace.enabled():
+                        sp = trace.span("sched.drain")
+                    handled = 1
                     needs = self._handle(ev)
                     if isinstance(ev, EventCommit):
                         needs = bool(self.unassigned or self.preassigned)
@@ -488,8 +494,11 @@ class Scheduler:
                         if nxt is None:
                             continue_draining = False
                         else:
+                            handled += 1
                             if self._handle(nxt) and dirty_since is None:
                                 dirty_since = now
+                    if sp is not None:
+                        sp.end(events=handled)
                 if dirty_since is not None and (
                         ev is None or now - dirty_since >= MAX_LATENCY):
                     # debounce elapsed with no new event, or max latency hit

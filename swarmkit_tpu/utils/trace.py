@@ -31,6 +31,12 @@ inside the C segment walk, never in per-entry WAL write loops — and
 device syncs get one `tick.device_sync` span per burst (the blocking
 value pull), never one per kernel.
 
+Armed, every span reads the calling thread's CPU clock
+(`time.thread_time()`) at start and end: the record's `cpu` beside its
+wall `dur` tells a stage that computes from one that waits (the GIL, an
+fsync, the device pull, a barrier). Span ids are a per-process random
+prefix plus a counter, so arming costs no syscall per span.
+
 Armed, a finished span goes two places:
 
   * the FLIGHT RECORDER — a bounded ring of completed-span records the
@@ -46,6 +52,7 @@ Span catalog and parent rules are documented in docs/observability.md.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 from ..analysis.lockgraph import make_lock
@@ -87,9 +94,15 @@ _STAGE_FAMILIES: tuple[tuple[str, str, str], ...] = (
 )
 
 
+# span and trace ids: a random per-process prefix (unique across the
+# node processes whose contexts meet over RPC and raft) plus a
+# process-wide counter (next() on itertools.count is atomic under the GIL)
+_ID_PREFIX = os.urandom(8).hex()
+_IDS = itertools.count(1)
+
+
 def _new_id() -> str:
-    # 64-bit hex, cheap and collision-safe at flight-recorder scale
-    return os.urandom(8).hex()
+    return f"{_ID_PREFIX}{next(_IDS):x}"
 
 
 class Span:
@@ -100,14 +113,16 @@ class Span:
     crashing its owner thread."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "_rec", "_t0", "_wall", "_on_stack", "_ended")
+                 "_rec", "_t0", "_wall", "_cpu0", "_thread", "_on_stack",
+                 "_ended")
 
     def __init__(self, rec: "FlightRecorder", name: str,
                  parent: "tuple[str, str] | Span | None", attrs: dict,
                  on_stack: bool):
         self.name = name
         self.attrs = attrs
-        parent = _coerce_ctx(parent)
+        if parent is not None:
+            parent = _coerce_ctx(parent)
         if parent is None:
             parent = _current_ctx()
         if parent is not None:
@@ -119,6 +134,8 @@ class Span:
         self._wall = rec.clock.monotonic() if rec.clock is not None \
             else time.time()
         self._t0 = time.perf_counter()
+        self._cpu0 = time.thread_time()
+        self._thread = threading.current_thread()
         self._on_stack = on_stack
         self._ended = False
         if on_stack:
@@ -148,10 +165,15 @@ class Span:
                     stack.remove(self)
                 except ValueError:
                     pass
+        # a span ended on another thread than it started on (raft.propose
+        # resolves on the raft worker) has no one thread's CPU time; it is
+        # filed under the thread that opened it
+        cpu = (time.thread_time() - self._cpu0
+               if threading.current_thread() is self._thread else None)
         self._rec.record(self.name, self._wall,
                          time.perf_counter() - self._t0,
                          self.trace_id, self.span_id, self.parent_id,
-                         self.attrs)
+                         self.attrs, cpu, self._thread.name)
 
     def __enter__(self) -> "Span":
         return self
@@ -191,10 +213,14 @@ class FlightRecorder:
     """Bounded ring of completed-span records.
 
     A record is a plain dict (codec/JSON-safe):
-      {name, t0, dur, trace, span, parent, thread, attrs}
-    `t0` is wall-clock seconds (or the injected clock's monotonic time —
-    tests pin expiry logic with FakeClock), `dur` is perf_counter
-    seconds. The ring is `capacity` records deep; old spans fall off —
+      {name, t0, dur, cpu, trace, span, parent, thread, attrs}
+    `thread` is the name of the thread that opened the span.
+    `t0` is wall-clock seconds (`time.time()`, the clock a profiler
+    trace's `profile_start_time` is on; or the injected clock's monotonic
+    time — tests pin expiry logic with FakeClock), `dur` is perf_counter
+    seconds, `cpu` the thread CPU seconds the span took (None for a
+    stage `rec()` filed pre-measured and for a span that ended on another
+    thread). The ring is `capacity` records deep; old spans fall off —
     exactly the crash-forensics shape: the TAIL near the wedge/failure
     is what matters.
     """
@@ -204,23 +230,19 @@ class FlightRecorder:
         self.clock = clock
         self._lock = make_lock('utils.trace.recorder')
         self._ring: list[dict] = []
-        self.spans_started = 0       # observability + the disarmed guard
+        self.spans_recorded = 0      # records filed, dropped ones included
         self.dropped = 0             # records that fell off the ring
-
-    def _count_start(self) -> None:
-        # spans open from many threads at once (tick, CommitWorker, rpc
-        # handlers); a bare += is a lost-update race on the counter
-        with self._lock:
-            self.spans_started += 1
 
     # ------------------------------------------------------------- writing
     def record(self, name: str, t0: float, dur: float, trace_id: str,
-               span_id: str, parent_id: str | None, attrs: dict) -> None:
-        rec = {"name": name, "t0": t0, "dur": dur, "trace": trace_id,
-               "span": span_id, "parent": parent_id,
-               "thread": threading.current_thread().name,
+               span_id: str, parent_id: str | None, attrs: dict,
+               cpu: float | None = None, thread: str | None = None) -> None:
+        rec = {"name": name, "t0": t0, "dur": dur, "cpu": cpu,
+               "trace": trace_id, "span": span_id, "parent": parent_id,
+               "thread": thread or threading.current_thread().name,
                "attrs": attrs}
         with self._lock:
+            self.spans_recorded += 1
             self._ring.append(rec)
             if len(self._ring) > self.capacity:
                 # trim in chunks: one del per capacity/8 appends, not one
@@ -229,9 +251,9 @@ class FlightRecorder:
                 del self._ring[:cut]
                 self.dropped += cut
         if dur > 0.0 and _REC is self:
-            # zero-duration point events (trace.event: raft.stage,
-            # raft.commit) are trace markers, not latency samples — they
-            # must not flood the derived stage histograms with 0s; and a
+            # a zero-duration record (a stage that measured nothing) is
+            # not a latency sample — it must not flood the derived stage
+            # histograms with 0s; and a
             # span ending into a RETIRED recorder (it straddled a
             # disarm) keeps its forensics record but must not grow the
             # histograms — those populate only while armed (CLAUDE.md)
@@ -277,12 +299,14 @@ class FlightRecorder:
         line, newest last (wedge monitor / chaos-failure output)."""
         lines = []
         for r in self.tail(n):
-            parent = f" <{r['parent'][:8]}" if r["parent"] else ""
+            # ids end in their per-process counter: the tail tells them
+            # apart
+            parent = f" <{r['parent'][-8:]}" if r["parent"] else ""
             attrs = "".join(f" {k}={v}" for k, v in r["attrs"].items())
             lines.append(
                 f"[{r['t0']:.6f} +{r['dur'] * 1e3:8.3f}ms] "
-                f"{r['name']} trace={r['trace'][:8]} "
-                f"span={r['span'][:8]}{parent}"
+                f"{r['name']} trace={r['trace'][-8:]} "
+                f"span={r['span'][-8:]}{parent}"
                 f" thread={r['thread']}{attrs}")
         return "\n".join(lines)
 
@@ -297,10 +321,14 @@ def _stack() -> list:
     return _tls.stack
 
 
-def _current_ctx() -> tuple[str, str] | None:
+def _current_stack() -> list | None:
     if getattr(_tls, "gen", -1) != _GEN:
         return None
-    s = getattr(_tls, "stack", None)
+    return getattr(_tls, "stack", None)
+
+
+def _current_ctx() -> tuple[str, str] | None:
+    s = _current_stack()
     if s:
         return s[-1].ctx()
     return None
@@ -322,22 +350,28 @@ def _coerce_ctx(parent) -> tuple[str, str] | None:
     return None
 
 
-# prefix -> resolved HistogramFamily, filled at first armed use (the
-# registry lookup + import per record was measurable armed overhead)
-_STAGE_FAMILY_CACHE: dict[str, Any] = {}
+# span name -> (resolved HistogramFamily, stage label), or None for a
+# name no family takes: filled at the name's first armed record (the
+# prefix scan and registry lookup per record were measurable armed
+# overhead)
+_STAGE_OF_NAME: dict[str, Any] = {}
 
 
 def _observe_stage(name: str, dur: float) -> None:
-    for prefix, family, help_ in _STAGE_FAMILIES:
-        if name.startswith(prefix):
-            fam = _STAGE_FAMILY_CACHE.get(prefix)
-            if fam is None:
+    try:
+        hit = _STAGE_OF_NAME[name]
+    except KeyError:
+        hit = None
+        for prefix, family, help_ in _STAGE_FAMILIES:
+            if name.startswith(prefix):
                 from . import metrics
 
-                fam = metrics.histogram_family(family, help_, ("stage",))
-                _STAGE_FAMILY_CACHE[prefix] = fam
-            fam.observe((name[len(prefix):] or name.rstrip("."),), dur)
-            return
+                hit = (metrics.histogram_family(family, help_, ("stage",)),
+                       (name[len(prefix):] or name.rstrip("."),))
+                break
+        _STAGE_OF_NAME[name] = hit
+    if hit is not None:
+        hit[0].observe(hit[1], dur)
 
 
 # ------------------------------------------------------------------ sites
@@ -353,7 +387,6 @@ def span(name: str, parent=None, **attrs):
     rec = _REC
     if rec is None:
         return NOOP
-    rec._count_start()
     return Span(rec, name, parent, attrs, on_stack=True)
 
 
@@ -364,7 +397,6 @@ def start(name: str, parent=None, **attrs):
     rec = _REC
     if rec is None:
         return None
-    rec._count_start()
     return Span(rec, name, parent, attrs, on_stack=False)
 
 
@@ -384,7 +416,6 @@ def rec(name: str, seconds: float, parent=None, **attrs) -> None:
     r = _REC
     if r is None:
         return
-    r._count_start()
     parent = _coerce_ctx(parent)
     if parent is None:
         parent = _current_ctx()
@@ -397,9 +428,16 @@ def rec(name: str, seconds: float, parent=None, **attrs) -> None:
              parent_id, attrs)
 
 
-def event(name: str, parent=None, **attrs) -> None:
-    """A zero-duration point annotation (e.g. `raft.stage`)."""
-    rec(name, 0.0, parent=parent, **attrs)
+def annotate(**attrs) -> None:
+    """Add attrs to the calling thread's innermost open span (a callee
+    stamping what it launched onto its caller's span). Armed only:
+    sites guard with `if trace.enabled():`, so a disarmed site builds no
+    kwargs dict."""
+    if _REC is None:
+        return
+    s = _current_stack()
+    if s:
+        s[-1].attrs.update(attrs)
 
 
 def wrap(name: str, fn: Callable[[], Any], parent=None, **attrs):
@@ -437,12 +475,13 @@ def arm(capacity: int = DEFAULT_CAPACITY, clock=None) -> FlightRecorder:
 
 
 def disarm() -> None:
-    global _REC, _RETIRED_TAIL
+    global _REC, _RETIRED
     with _REG_LOCK:
         if _REC is not None:
-            # keep the tail across the disarm: report hooks (the chaos
-            # makereport section) run AFTER the harness disarmed
-            _RETIRED_TAIL = _REC.tail_text(64)
+            # keep the recorder readable across the disarm: report hooks
+            # (the chaos makereport section) and a window's readers run
+            # AFTER the owner disarmed
+            _RETIRED = _REC
         _REC = None
     # a disarm must not leave implicit parents behind for the next arm
     s = getattr(_tls, "stack", None)
@@ -456,6 +495,12 @@ def active() -> bool:
 
 def recorder() -> FlightRecorder | None:
     return _REC
+
+
+def retired() -> FlightRecorder | None:
+    """The recorder the last disarm() retired, until clear_retired_tail():
+    a traced window's records stay readable after the window closed."""
+    return _RETIRED
 
 
 @contextmanager
@@ -477,26 +522,24 @@ def tail_text(n: int = 64) -> str:
     return r.tail_text(n) if r is not None else ""
 
 
-# tail captured by the most recent disarm() — lets a post-teardown
-# report hook still show what the retired recorder held
-_RETIRED_TAIL = ""
+# the recorder the most recent disarm() retired — lets a post-teardown
+# report hook still show what it held
+_RETIRED: FlightRecorder | None = None
 
 
 def last_tail_text(n: int = 64) -> str:
-    """The armed tail, falling back to the tail captured at the last
-    disarm — for hooks that run after the owning harness already
+    """The armed tail, falling back to the tail of the recorder the last
+    disarm retired — for hooks that run after the owning harness already
     disarmed (the conftest chaos report section). Clear the retired
-    copy with `clear_retired_tail()` before each scope that must not
+    recorder with `clear_retired_tail()` before each scope that must not
     see a stale predecessor's spans."""
-    r = _REC
-    if r is not None:
-        return r.tail_text(n)
-    return _RETIRED_TAIL
+    r = _REC if _REC is not None else _RETIRED
+    return r.tail_text(n) if r is not None else ""
 
 
 def clear_retired_tail() -> None:
-    global _RETIRED_TAIL
-    _RETIRED_TAIL = ""
+    global _RETIRED
+    _RETIRED = None
 
 
 # ---------------------------------------------------------------- env var

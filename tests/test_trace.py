@@ -6,11 +6,11 @@ histograms, and the /metrics exposition satellites.
 The acceptance pair:
 
   * a 3-node raft cluster produces ONE causal trace covering
-    propose → WAL-fsync → commit → apply across node boundaries
-    (the context rides the Entry through replication);
+    propose → WAL-fsync → apply on every node (the context rides the
+    Entry through replication);
   * a failpoints-style guard pins that tracing OFF allocates no Span
-    and files no record on the tick, dispatcher-flush, and raft
-    ready-loop hot paths.
+    and files no record on the tick, dispatcher-flush, raft ready-flush,
+    event-loop burst and scheduler drain hot paths.
 """
 import importlib.util
 import json
@@ -34,7 +34,7 @@ def test_disarmed_surface_is_inert():
     assert trace.start("never.armed") is None
     assert trace.ctx() is None
     trace.rec("never.armed", 0.01)          # no-op, no error
-    trace.event("never.armed")
+    trace.annotate(never="armed")
     fn = object()
     assert trace.wrap("never.armed", fn) is fn
     assert trace.tail_text() == ""
@@ -80,7 +80,7 @@ def test_ring_is_bounded_and_counts_drops():
         snap = rec.snapshot()
         assert len(snap) <= 64
         assert rec.dropped == 500 - len(snap)
-        assert rec.spans_started == 500
+        assert rec.spans_recorded == 500
         # the TAIL survived — crash forensics wants the newest spans
         assert snap[-1]["attrs"]["i"] == 499
 
@@ -157,10 +157,10 @@ def test_malformed_wire_ctx_never_raises():
         for bad in (5, "just-a-string", ["one"], ("a", "b", "c"),
                     (1, 2), {"t": "x"}, (None, "y")):
             trace.rec("raft.apply", 0.001, parent=bad)
-            trace.event("raft.commit", parent=bad)
+            trace.start("raft.propose", parent=bad).end()
             with trace.span("rpc.server", parent=bad):
                 pass
-        assert rec.spans_started == 3 * 7   # all filed, none raised
+        assert rec.spans_recorded == 3 * 7  # all filed, none raised
         # end-to-end: a committed entry carrying a garbage ctx still
         # applies (the leader below echoes whatever rides the proposal)
         cluster = RaftCluster(3, seed=31)
@@ -198,17 +198,17 @@ def test_stage_histograms_derived_from_spans():
     n_encode = tick_fam.child(("encode",))._n
     n_fsync = raft_fam.child(("wal_fsync",))._n
     n_wheel = disp_fam.child(("wheel.tick",))._n
-    n_commit = raft_fam.child(("commit",))._n
+    n_apply = raft_fam.child(("apply",))._n
     with trace.armed():
         trace.rec("tick.encode", 0.002)
         trace.rec("raft.wal_fsync", 0.001)
         trace.rec("hb.wheel.tick", 0.0005)
-        # zero-duration point events are markers, never latency samples
-        trace.event("raft.commit", node=1)
+        # zero-duration records are markers, never latency samples
+        trace.rec("raft.apply", 0.0, node=1)
     assert tick_fam.child(("encode",))._n == n_encode + 1
     assert raft_fam.child(("wal_fsync",))._n == n_fsync + 1
     assert disp_fam.child(("wheel.tick",))._n == n_wheel + 1
-    assert raft_fam.child(("commit",))._n == n_commit
+    assert raft_fam.child(("apply",))._n == n_apply
 
 
 # ------------------------------------------- disarmed-overhead acceptance
@@ -326,12 +326,148 @@ def test_failing_wave_span_reaches_recorder():
             pipe.close()
 
 
+def test_disarmed_zero_allocation_on_raft_ready_flush_with_wal(tmp_path):
+    """The ready flush with a WAL behind it (append + fsync, commit
+    advance, apply) and the proposer's propose path, tracing off."""
+    from swarmkit_tpu.raft.proposer import RaftProposer
+    from swarmkit_tpu.raft.storage import RaftStorage
+    from swarmkit_tpu.raft.testutils import RaftCluster
+
+    assert not trace.active()
+    with _SpanAllocGuard():
+        cluster = RaftCluster(
+            1, storages={1: RaftStorage(str(tmp_path / "n1"))}, seed=7)
+        proposer = RaftProposer(cluster.nodes[1])
+        cluster.elect(1)
+        handles = [proposer.propose_async([], lambda **kw: None)
+                   for _ in range(3)]
+        cluster.settle()
+        assert all(h.done for h in handles)
+
+
+class _BurstProbe:
+    """An EventLoopComponent that counts its handle and flush calls."""
+
+    @staticmethod
+    def make(store):
+        from swarmkit_tpu.orchestrator.base import EventLoopComponent
+
+        class Probe(EventLoopComponent):
+            name = "burst-probe"
+
+            def __init__(self, store):
+                super().__init__(store)
+                self.handled = 0
+                self.flushes = 0
+
+            def handle(self, event):
+                self.handled += 1
+
+            def flush_events(self):
+                self.flushes += 1
+
+        return Probe(store)
+
+
+def _create_tasks(store, prefix, n):
+    from swarmkit_tpu.api.objects import Task
+
+    def run(tx):
+        for i in range(n):
+            tx.create(Task(id=f"{prefix}-{i:03d}"))
+
+    store.update(run)
+
+
+def _wait_for(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.01)
+
+
+def test_disarmed_zero_allocation_on_event_loop_burst():
+    """EventLoopComponent's drained burst (handle calls + flush_events)
+    with tracing off."""
+    from swarmkit_tpu.store.memory import MemoryStore
+
+    assert not trace.active()
+    store = MemoryStore()
+    with _SpanAllocGuard():
+        probe = _BurstProbe.make(store)
+        probe.start()
+        try:
+            _create_tasks(store, "quiet", 20)
+            _wait_for(lambda: probe.handled >= 20)
+        finally:
+            probe.stop()
+        assert probe.flushes >= 1
+
+
+def _scheduler_store(n_nodes=4):
+    from swarmkit_tpu.api.objects import Node
+    from swarmkit_tpu.api.types import NodeAvailability, NodeStatusState
+    from swarmkit_tpu.store.memory import MemoryStore
+
+    store = MemoryStore()
+
+    def seed(tx):
+        for i in range(n_nodes):
+            n = Node(id=f"n{i:02d}")
+            n.status.state = NodeStatusState.READY
+            n.spec.availability = NodeAvailability.ACTIVE
+            tx.create(n)
+
+    store.update(seed)
+    return store
+
+
+def _pending_tasks(store, prefix, n):
+    from swarmkit_tpu.api.objects import Task
+    from swarmkit_tpu.api.types import TaskState
+
+    def run(tx):
+        for w in range(n):
+            t = Task(id=f"{prefix}-{w:02d}", service_id="s1", slot=w + 1)
+            t.desired_state = TaskState.RUNNING
+            t.status.state = TaskState.PENDING
+            tx.create(t)
+
+    store.update(run)
+
+
+def _all_assigned(store, prefix, n):
+    from swarmkit_tpu.api.types import TaskState
+
+    mine = [t for t in store.view(lambda tx: tx.find_tasks())
+            if t.id.startswith(prefix)]
+    return len(mine) == n and all(
+        t.status.state == TaskState.ASSIGNED and t.node_id for t in mine)
+
+
+def test_disarmed_zero_allocation_on_scheduler_drain():
+    """Scheduler.run's event drain between ticks (and the ticks it
+    triggers) with tracing off."""
+    from swarmkit_tpu.scheduler.scheduler import Scheduler
+
+    assert not trace.active()
+    store = _scheduler_store()
+    with _SpanAllocGuard():
+        sched = Scheduler(store, backend="cpu")
+        sched.start()
+        try:
+            _pending_tasks(store, "quiet", 6)
+            _wait_for(lambda: _all_assigned(store, "quiet", 6), 30.0)
+        finally:
+            sched.stop()
+
+
 # ------------------------------------------------- raft causal trace (3n)
-def test_raft_3node_causal_trace_propose_fsync_commit_apply(tmp_path):
-    """Acceptance: ONE causal trace covers propose → WAL-fsync → commit
-    → apply, across node boundaries — the context rides the replicated
-    Entry, so the followers' fsync/apply spans share the leader-side
-    proposal's trace id."""
+def test_raft_3node_causal_trace_propose_fsync_apply(tmp_path):
+    """Acceptance: ONE causal trace covers propose → WAL-fsync → apply
+    on every node — the context rides the replicated Entry, so each
+    node's ready flush (and the fsync inside it) and its apply span share
+    the leader-side proposal's trace id."""
     from swarmkit_tpu.raft.storage import RaftStorage
     from swarmkit_tpu.raft.testutils import RaftCluster
 
@@ -355,20 +491,24 @@ def test_raft_3node_causal_trace_propose_fsync_commit_apply(tmp_path):
     for r in mine:
         by_name.setdefault(r["name"], []).append(r)
     # the full causal chain, in one trace
-    for stage in ("raft.propose", "raft.stage", "raft.wal_fsync",
-                  "raft.commit", "raft.apply"):
+    for stage in ("raft.propose", "raft.ready", "raft.wal_fsync",
+                  "raft.apply"):
         assert stage in by_name, (stage, sorted(by_name))
     # across node boundaries: the entry replicated with its ctx, so every
     # member persisted and applied under THIS trace
     fsync_nodes = {r["attrs"]["node"] for r in by_name["raft.wal_fsync"]}
     apply_nodes = {r["attrs"]["node"] for r in by_name["raft.apply"]}
-    commit_nodes = {r["attrs"]["node"] for r in by_name["raft.commit"]}
     assert fsync_nodes == {1, 2, 3}
     assert apply_nodes == {1, 2, 3}
-    assert commit_nodes == {1, 2, 3}
-    # parent links: stage/fsync point at the proposal span
-    assert {r["parent"] for r in by_name["raft.stage"]} == {sp.span_id}
-    assert {r["parent"] for r in by_name["raft.wal_fsync"]} == {sp.span_id}
+    # parent links: each node's flush and apply point at the proposal,
+    # each fsync at its own node's flush
+    assert {r["parent"] for r in by_name["raft.ready"]} == {sp.span_id}
+    assert {r["parent"] for r in by_name["raft.apply"]} == {sp.span_id}
+    ready = {r["span"]: r["attrs"]["node"] for r in by_name["raft.ready"]}
+    for r in by_name["raft.wal_fsync"]:
+        assert ready[r["parent"]] == r["attrs"]["node"]
+    # the removed per-entry point events stay gone
+    assert not {"raft.stage", "raft.commit"} & {r["name"] for r in recs}
 
 
 def test_entry_trace_ctx_survives_wire_codec():
@@ -405,6 +545,235 @@ def test_proposer_opens_propose_root_span(tmp_path):
         assert handle.done and fired
         names = [r["name"] for r in rec.snapshot()]
     assert "raft.propose" in names
+
+
+def test_propose_span_counts_actions_under_callers_span():
+    """raft.propose carries the proposal's action count and parents to
+    the caller's open span: the layer that proposed is on record."""
+    from swarmkit_tpu.raft.proposer import RaftProposer
+    from swarmkit_tpu.raft.testutils import RaftCluster
+
+    cluster = RaftCluster(1, seed=5)
+    proposer = RaftProposer(cluster.nodes[1])
+    cluster.elect(1)
+    with trace.armed() as rec:
+        with trace.span("loop.allocator") as loop_sp:
+            handle = proposer.propose_async(
+                iter([{"a": 1}, {"a": 2}, {"a": 3}]), lambda **kw: None)
+        cluster.settle()
+        assert handle.done
+        recs = {r["name"]: r for r in rec.snapshot()}
+    prop = recs["raft.propose"]
+    assert prop["attrs"]["actions"] == 3
+    assert prop["parent"] == loop_sp.span_id
+    # filed under the thread that proposed
+    assert prop["thread"] == recs["loop.allocator"]["thread"]
+
+
+def test_raft_ready_span_wraps_fsync_and_apply(tmp_path):
+    """One raft.ready per flush, with the batch size; the group fsync is
+    its child, and the apply span nests inside it on the same thread."""
+    from swarmkit_tpu.raft.proposer import RaftProposer
+    from swarmkit_tpu.raft.storage import RaftStorage
+    from swarmkit_tpu.raft.testutils import RaftCluster
+
+    cluster = RaftCluster(
+        1, storages={1: RaftStorage(str(tmp_path / "n1"))}, seed=9)
+    proposer = RaftProposer(cluster.nodes[1])
+    cluster.elect(1)
+    with trace.armed() as rec:
+        handles = [proposer.propose_async([{"k": i}], lambda **kw: None)
+                   for i in range(3)]
+        cluster.settle()
+        assert all(h.done for h in handles)
+        recs = rec.snapshot()
+    readies = [r for r in recs if r["name"] == "raft.ready"]
+    fsyncs = [r for r in recs if r["name"] == "raft.wal_fsync"]
+    applies = [r for r in recs if r["name"] == "raft.apply"]
+    assert readies and fsyncs and len(applies) == 3
+    by_span = {r["span"]: r for r in readies}
+    # every entry went through one flush's WAL append
+    assert sum(r["attrs"]["entries"] for r in readies) >= 3
+    for f in fsyncs:
+        parent = by_span[f["parent"]]
+        assert f["attrs"]["entries"] == parent["attrs"]["entries"]
+        assert parent["t0"] <= f["t0"]
+        assert f["t0"] + f["dur"] <= parent["t0"] + parent["dur"] + 1e-6
+    props = {r["span"] for r in recs if r["name"] == "raft.propose"}
+    for a in applies:
+        assert a["parent"] in props
+        assert any(r["thread"] == a["thread"] and r["t0"] <= a["t0"]
+                   and a["t0"] + a["dur"] <= r["t0"] + r["dur"] + 1e-6
+                   for r in readies)
+
+
+def test_event_loop_burst_span_per_burst():
+    """EventLoopComponent: one loop.<name> span per drained burst, its
+    events attr the burst's size — never one span per event."""
+    from swarmkit_tpu.store.memory import MemoryStore
+
+    store = MemoryStore()
+    with trace.armed() as rec:
+        probe = _BurstProbe.make(store)
+        probe.start()
+        try:
+            _create_tasks(store, "loud", 20)
+            _wait_for(lambda: probe.handled >= 20)
+        finally:
+            probe.stop()
+        spans = [r for r in rec.snapshot()
+                 if r["name"] == "loop.burst-probe"]
+    assert len(spans) == probe.flushes
+    assert sum(r["attrs"]["events"] for r in spans) == probe.handled
+    # one transaction's events arrive together: fewer bursts than events
+    assert len(spans) < probe.handled
+    assert all(r["thread"] == "burst-probe" for r in spans)
+
+
+def test_sched_drain_span_counts_handled_events():
+    """Scheduler.run: one sched.drain span per burst handled between
+    ticks, with the events it handled; files under tick_stage_seconds."""
+    from swarmkit_tpu.scheduler.scheduler import Scheduler
+    from swarmkit_tpu.utils.metrics import histogram_family
+
+    fam = histogram_family("tick_stage_seconds")
+    n_drain = fam.child(("drain",))._n
+    store = _scheduler_store()
+    with trace.armed() as rec:
+        sched = Scheduler(store, backend="cpu")
+        handled = []
+        orig = sched._handle
+
+        def counting(ev):
+            handled.append(ev)
+            return orig(ev)
+
+        sched._handle = counting
+        sched.start()
+        try:
+            _pending_tasks(store, "drain", 6)
+            _wait_for(lambda: _all_assigned(store, "drain", 6), 30.0)
+        finally:
+            sched.stop()
+        recs = rec.snapshot()
+    drains = [r for r in recs if r["name"] == "sched.drain"]
+    assert drains
+    assert sum(r["attrs"]["events"] for r in drains) == len(handled)
+    assert all(r["thread"] == "scheduler" for r in drains)
+    assert fam.child(("drain",))._n > n_drain
+    # drains and ticks take turns on one thread: never nested
+    ticks = [r for r in recs if r["name"] == "sched.tick"]
+    for d in drains:
+        assert d["parent"] is None or d["parent"] not in {
+            t["span"] for t in ticks}
+
+
+def test_span_cpu_time_against_wall_time():
+    """Every span records its thread's CPU seconds beside the wall
+    seconds: near the wall time for a busy span, near zero for one that
+    waits; pre-measured stages and cross-thread spans carry None."""
+    with trace.armed() as rec:
+        with trace.span("busy"):
+            t = time.perf_counter()
+            x = 0
+            while time.perf_counter() - t < 0.05:
+                x += 1
+        with trace.span("waits"):
+            time.sleep(0.05)
+        trace.rec("premeasured", 0.01)
+        sp = trace.start("crosses")
+        th = threading.Thread(target=sp.end)
+        th.start()
+        th.join()
+        recs = {r["name"]: r for r in rec.snapshot()}
+    busy, waits = recs["busy"], recs["waits"]
+    assert 0 < busy["cpu"] <= busy["dur"]
+    assert busy["cpu"] > 0.5 * busy["dur"]
+    assert 0 <= waits["cpu"] < 0.5 * waits["dur"]
+    assert recs["premeasured"]["cpu"] is None
+    assert recs["crosses"]["cpu"] is None
+
+
+def test_span_ids_unique_with_process_prefix():
+    """Ids are a per-process random prefix plus a counter: unique within
+    the process (threads included), and another process draws another
+    prefix."""
+    import subprocess
+    import sys
+
+    def open_spans():
+        for _ in range(200):
+            with trace.span("a"):
+                pass
+
+    with trace.armed() as rec:
+        threads = [threading.Thread(target=open_spans) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=10)
+            assert not t.is_alive()
+        recs = rec.snapshot()
+    ids = [r["span"] for r in recs] + [r["trace"] for r in recs]
+    assert len(ids) == 2 * 800 and len(set(ids)) == len(ids)
+    assert all(i.startswith(trace._ID_PREFIX) for i in ids)
+    other = subprocess.run(
+        [sys.executable, "-c",
+         "from swarmkit_tpu.utils import trace; print(trace._ID_PREFIX)"],
+        capture_output=True, text=True, timeout=60,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert other.returncode == 0, other.stderr
+    assert other.stdout.strip() not in ("", trace._ID_PREFIX)
+
+
+def test_retired_recorder_readable_after_disarm():
+    """A traced window's readers run after the window closed: the last
+    disarmed recorder stays readable until cleared."""
+    with trace.armed() as rec:
+        trace.rec("tick.encode", 0.001)
+    assert trace.retired() is rec
+    assert [r["name"] for r in trace.retired().snapshot()] == [
+        "tick.encode"]
+    trace.clear_retired_tail()
+    assert trace.retired() is None
+
+
+def test_dispatch_span_carries_program_shapes():
+    """ResidentPlacement stamps the static shapes of the tick and pull
+    programs onto the caller's tick.dispatch span, with new_shape the
+    first time it dispatches a key."""
+    import random as _random
+
+    from test_encoder_incremental import NOW, make_info
+    from test_resident import apply_tick, plain_group
+
+    from swarmkit_tpu.ops.resident import ResidentPlacement
+    from swarmkit_tpu.scheduler.encode import IncrementalEncoder
+
+    rng = _random.Random(7)
+    infos = [make_info(rng, i) for i in range(16)]
+    enc = IncrementalEncoder()
+    rp = ResidentPlacement(enc)
+    with trace.armed() as rec:
+        p1 = enc.encode(infos, [plain_group("steady", 1, 8)], now=NOW)
+        with trace.span("tick.dispatch"):
+            h = rp.schedule_async(p1)
+        apply_tick(enc, rp, infos, p1, h.get())
+        p2 = enc.encode(infos, [plain_group("steady", 2, 8)], now=NOW)
+        with trace.span("tick.dispatch"):
+            rp.schedule_async(p2).get()
+        first, second = [r["attrs"] for r in rec.snapshot()
+                         if r["name"] == "tick.dispatch"]
+    assert first["new_shape"] is True
+    assert first["new_tick"] and first["new_pull"]
+    assert len(first["tick_shape"]) == 10 and len(first["tick_flags"]) == 6
+    assert first["pull"][0] in ("sparse", "slice")
+    assert first["pull"][1:3] == (1, 16)
+    # the same shapes again: no new_shape
+    assert second["tick_shape"] == first["tick_shape"]
+    assert second["pull"] == first["pull"]
+    assert "new_shape" not in second
 
 
 # ----------------------------------------------------- rpc span propagation
