@@ -32,10 +32,20 @@ from ..api.types import NodeStatusState, TaskState
 from ..store import by
 from ..store.memory import MAX_CHANGES_PER_TRANSACTION
 from ..orchestrator.base import EventLoopComponent
-from ..utils import failpoints, lifecycle
+from ..utils import failpoints, lifecycle, trace
+from ..utils.leadership import leadership_lost
+from ..utils.metrics import histogram
 from .ipam import IPAM, IPAMError
 
 log = logging.getLogger("swarmkit_tpu.allocator")
+
+# tasks moved NEW->PENDING per committed allocator transaction: a burst
+# of NEW-task events coalesces into one transaction per
+# MAX_CHANGES_PER_TRANSACTION tasks
+_batch_tasks = histogram(
+    "swarm_allocator_batch_tasks",
+    "Tasks moved to PENDING per allocator transaction",
+    buckets=(1, 2, 5, 10, 20, 50, 100, 150, 200))
 
 DYNAMIC_PORT_START = 30000  # reference portallocator.go dynamic range
 DYNAMIC_PORT_END = 32767
@@ -158,6 +168,12 @@ class Allocator(EventLoopComponent):
         # un-primed fallback (primed by on_start's full pass)
         self._deferred_services: set[str] = set()
         self._deferred_primed = False
+        # NEW tasks of the current burst (an ordered set), allocated
+        # together by flush_events or before the next event that writes
+        self._new_tasks: dict[str, None] = {}
+        # tasks the current burst handed to _allocate_tasks (`tasks=` on
+        # its loop.allocator span)
+        self._burst_tasks = 0
 
     def setup(self, tx):
         # ONE consistent snapshot: the NEW subset derives from the full task
@@ -220,11 +236,23 @@ class Allocator(EventLoopComponent):
 
     def handle(self, event):
         obj = getattr(event, "obj", None)
+        if not isinstance(obj, (Task, Service, Network, Node)):
+            return
+        if isinstance(obj, Task) and isinstance(event, (EventCreate,
+                                                        EventUpdate)):
+            if obj.status.state == TaskState.NEW:
+                # coalesce: a burst's NEW tasks move to PENDING in one
+                # transaction per MAX_CHANGES_PER_TRANSACTION tasks
+                self._new_tasks[obj.id] = None
+                return
+            if obj.status.state < TaskState.COMPLETE:
+                return                 # in flight: nothing to do
+        # every other event acts: allocate the queued tasks first, so the
+        # writes keep the order of the events that caused them
+        self._flush_new_tasks(retry_later=True)
         if isinstance(event, (EventCreate, EventUpdate)):
             if isinstance(obj, Task):
-                if obj.status.state == TaskState.NEW:
-                    self._allocate_tasks([obj.id])
-                elif obj.status.state >= TaskState.COMPLETE:
+                if obj.status.state >= TaskState.COMPLETE:
                     # dead task: its attachment addresses return to the pool
                     # (network.go doTaskAlloc handles task death the same way)
                     self._release_task_attachments(obj)
@@ -260,6 +288,40 @@ class Allocator(EventLoopComponent):
                             freed = True
                 if freed:
                     self._retry_after_free()
+
+    def flush_events(self):
+        try:
+            self._flush_new_tasks()
+        finally:
+            if trace.enabled():
+                trace.annotate(tasks=self._burst_tasks)
+            self._burst_tasks = 0
+
+    def idle(self):
+        # retry a re-queued burst even when no further event arrives
+        self.flush_events()
+
+    def _flush_new_tasks(self, retry_later: bool = False):
+        """Allocate the queued NEW tasks. A crashed allocation puts its
+        ids back for idle() or the next burst (the replicated
+        orchestrator's crashed-burst contract): `_allocate_tasks`
+        releases the grants of a chunk that did not commit, and a task
+        already PENDING is skipped on the retry. With `retry_later` the
+        failure is logged instead of raised, so the event that triggered
+        the flush is still handled."""
+        if not self._new_tasks:
+            return
+        ids = list(self._new_tasks)
+        self._new_tasks.clear()
+        self._burst_tasks += len(ids)
+        try:
+            self._allocate_tasks(ids)
+        except Exception as exc:
+            self._new_tasks.update(dict.fromkeys(ids))
+            if not retry_later or leadership_lost(exc):
+                raise
+            log.exception("allocator: %d queued tasks failed; retried "
+                          "later", len(ids))
 
     def _release_task_attachments(self, task: Task, deleted: bool = False):
         """Return a dead task's addresses AND persist the release by
@@ -553,10 +615,13 @@ class Allocator(EventLoopComponent):
         # and file them as ONE batched record after the store batch (the
         # decision boundary); disarmed, no list is ever built
         moved: list[str] | None = [] if lifecycle.enabled() else None
+        # Batch commits every MAX_CHANGES_PER_TRANSACTION updates: task i
+        # rides transaction i // MAX_CHANGES_PER_TRANSACTION
+        per_tx = [0] * -(-len(task_ids) // MAX_CHANGES_PER_TRANSACTION)
 
         def cb(batch):
-            for tid in task_ids:
-                def move_one(tx, tid=tid):
+            for i, tid in enumerate(task_ids):
+                def move_one(tx, tid=tid, i=i):
                     t = tx.get_task(tid)
                     if t is None or t.status.state != TaskState.NEW:
                         return
@@ -597,12 +662,16 @@ class Allocator(EventLoopComponent):
                     t.status.state = TaskState.PENDING
                     t.status.message = "pending task scheduling"
                     tx.update(t)
+                    per_tx[i // MAX_CHANGES_PER_TRANSACTION] += 1
                     if moved is not None:
                         moved.append(tid)
 
                 batch.update(move_one)
 
         self.store.batch(cb)
+        for n in per_tx:
+            if n:
+                _batch_tasks.observe(n)
         if moved:
             lifecycle.record_batch(TaskState.PENDING, moved)
 
@@ -622,10 +691,13 @@ class Allocator(EventLoopComponent):
         for off in range(0, len(task_ids), MAX_CHANGES_PER_TRANSACTION):
             chunk = task_ids[off:off + MAX_CHANGES_PER_TRANSACTION]
             granted: list[tuple[str, str]] = []
+            staged = 0
+
+            def run(tx, chunk=chunk, granted=granted):
+                nonlocal staged
+                staged = self._alloc_chunk_in_tx(tx, chunk, granted, moved)
             try:
-                self.store.update(
-                    lambda tx, chunk=chunk: self._alloc_chunk_in_tx(
-                        tx, chunk, granted, moved))
+                self.store.update(run)
             except BaseException:
                 # the transaction never committed: hand every grant of
                 # this chunk back (release is an idempotent discard, so
@@ -633,10 +705,13 @@ class Allocator(EventLoopComponent):
                 for net_id, addr in granted:
                     self.ipam.release(net_id, addr)
                 raise
+            if staged:
+                _batch_tasks.observe(staged)
         if moved:
             lifecycle.record_batch(TaskState.PENDING, moved)
 
-    def _alloc_chunk_in_tx(self, tx, chunk, granted, moved):
+    def _alloc_chunk_in_tx(self, tx, chunk, granted, moved) -> int:
+        """Stage the chunk's NEW->PENDING moves; returns how many."""
         # pass 1: in-tx validation (same gates as the scalar move_one)
         # and per-network demand aggregation
         plans = []
@@ -673,6 +748,7 @@ class Allocator(EventLoopComponent):
                 bulk[nid] = addrs[::-1]  # pop() consumes in grant order
         failpoints.fp("alloc.batch.commit")
         # pass 3: distribute in task order and stage the store writes
+        staged = 0
         for t, service, nets in plans:
             attachments = []
             if bulk is not None:
@@ -711,5 +787,7 @@ class Allocator(EventLoopComponent):
             t.status.state = TaskState.PENDING
             t.status.message = "pending task scheduling"
             tx.update(t)
+            staged += 1
             if moved is not None:
                 moved.append(t.id)
+        return staged

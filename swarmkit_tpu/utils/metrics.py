@@ -195,11 +195,12 @@ _families: dict[str, object] = {}
 _registry_lock = make_lock('utils.metrics.registry_lock')
 
 
-def histogram(name: str, help_: str = "") -> Histogram:
+def histogram(name: str, help_: str = "",
+              buckets: tuple = DEFAULT_BUCKETS) -> Histogram:
     with _registry_lock:
         h = _registry.get(name)
         if h is None:
-            h = Histogram(name, help_)
+            h = Histogram(name, help_, buckets)
             _registry[name] = h
         return h
 
