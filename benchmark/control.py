@@ -11,12 +11,14 @@ checks two placements of the same services on the same fleet: the
 program's, read back from its store, and the control's. The control is a
 plain placer put in the program's place that breaks one of the
 configuration's guarantees: it honours every node's capacity but ignores
-placement constraints and spread preferences, choosing among the nodes with
-room at random. The program's numbers give a limit's lower reading; the
-control's give its upper one, and the control has to come out as not
-correct. Prints, as its last line, one JSON object with both sets of numbers
-and both verdicts; exits 0 only where the program is correct and the
-control is not.
+placement constraints, spread preferences and host-mode ports, choosing
+among the nodes with room at random. It keeps the program's addresses and
+ports, so where the configuration's kinds publish host-mode ports it has to
+come out not correct on `host_port_conflicts` too. The program's numbers
+give a limit's lower reading; the control's give its upper one, and the
+control has to come out as not correct. Prints, as its last line, one JSON
+object with both sets of numbers and both verdicts; exits 0 only where the
+program is correct and the control is not.
 """
 from __future__ import annotations
 

@@ -2,7 +2,12 @@
 
 The configuration lists its service kinds (constraints and spread
 preferences) and the reservation of every replica (scheduler_perf's
-pod-default: 0.1 core, 500 MiB). No host-published ports. Sizes follow a
+pod-default: 0.1 core, 500 MiB). A kind may also name the overlay networks
+its tasks attach to (`networks`), its endpoint mode (`endpoint_mode`, `vip`
+or `dnsrr`) and the ports it publishes (`ports`: target, published, mode,
+protocol; published 0 asks the allocator for a dynamic port, and every
+service of the kind publishes a fixed one as given). A kind without these
+keys makes the same spec as one from before they existed. Sizes follow a
 Zipf law given by the traffic file (s = 1.2 over 1..1000 replicas, mean
 about 72).
 
@@ -63,17 +68,32 @@ class ServiceStream:
             "constraints": [list(c) for c in k["constraints"]],
             "prefs": list(k["prefs"]),
         }
+        if "networks" in k:
+            spec["networks"] = list(k["networks"])
+        if "endpoint_mode" in k:
+            spec["endpoint_mode"] = k["endpoint_mode"]
+        if "ports" in k:
+            spec["ports"] = [{"target": int(q["target"]),
+                              "published": int(q.get("published", 0)),
+                              "mode": q.get("mode", "ingress"),
+                              "protocol": q.get("protocol", "tcp")}
+                             for q in k["ports"]]
         self.issued += 1
         return spec
 
 
-def to_spec(plain: dict):
-    """The program's ServiceSpec for a plain spec."""
+def to_spec(plain: dict, network_ids: dict | None = None):
+    """The program's ServiceSpec for a plain spec; network_ids maps the
+    names of the configuration's networks to the ids the store gave them
+    (the control API resolves an attachment's target by id)."""
     from swarmkit_tpu.api.specs import (
         Annotations,
         ContainerSpec,
+        EndpointSpec,
+        NetworkAttachmentConfig,
         Placement,
         PlacementPreference,
+        PortConfig,
         ServiceSpec,
         TaskSpec,
     )
@@ -87,5 +107,16 @@ def to_spec(plain: dict):
                      for k, op, v in plain["constraints"]],
         preferences=[PlacementPreference(spread_descriptor=f"node.labels.{p}")
                      for p in plain["prefs"]])
-    return ServiceSpec(annotations=Annotations(name=plain["name"]),
+    if plain.get("networks"):
+        task.networks = [NetworkAttachmentConfig(target=network_ids[n])
+                         for n in plain["networks"]]
+    spec = ServiceSpec(annotations=Annotations(name=plain["name"]),
                        task=task, replicas=plain["replicas"])
+    if "endpoint_mode" in plain or "ports" in plain:
+        spec.endpoint = EndpointSpec(
+            mode=plain.get("endpoint_mode", "vip"),
+            ports=[PortConfig(protocol=p["protocol"], target_port=p["target"],
+                              published_port=p["published"],
+                              publish_mode=p["mode"])
+                   for p in plain.get("ports", [])])
+    return spec

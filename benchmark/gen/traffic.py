@@ -16,13 +16,15 @@ class Client:
     on one thread, and records each call."""
 
     def __init__(self, control, collector, cfg: dict, traffic: dict,
-                 seed: int, prefix: str, block: int):
+                 seed: int, prefix: str, block: int,
+                 network_ids: dict | None = None):
         if traffic["loop"] != "closed":
             raise ValueError(f"traffic loop {traffic['loop']!r} is not "
                              f"generated")
         self._control = control
         self._collector = collector
         self._limit = int(traffic["outstanding_tasks"])
+        self._network_ids = network_ids
         self.stream = ServiceStream(cfg, traffic["sizes"], seed, block,
                                     prefix)
         self.created: list[dict] = []    # one record per create call
@@ -32,7 +34,7 @@ class Client:
         self._thread: threading.Thread | None = None
 
     def _create(self, plain: dict) -> None:
-        spec = to_spec(plain)
+        spec = to_spec(plain, self._network_ids)
         t_start = time.time()
         svc = self._control.create_service(spec)
         t_end = time.time()

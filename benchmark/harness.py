@@ -12,6 +12,30 @@ async commit, its own node drained), seeds the fleet through store
 transactions, warms up on the cell's own traffic, drives the control API for
 the window, waits for what is due, reads the placement back from the leader's
 raft-committed store and hands it to `check.py`.
+
+A configuration may give its services networks and endpoints. Every key is
+optional; a configuration without them runs as one from before they existed:
+
+  networks                 [{"name", "subnet", "ingress": bool}]: an entry
+                           with ingress true replaces the manager's default
+                           ingress network (whose /24 holds 253 addresses)
+                           before any node is seeded, so every node's
+                           ingress attachment comes from its subnet; the
+                           others are overlay networks created through
+                           ControlAPI.create_network with that subnet
+  service_kinds[].networks       names from `networks` the kind's tasks
+                                 attach to
+  service_kinds[].endpoint_mode  "vip" (the default) or "dnsrr"
+  service_kinds[].ports    [{"target", "published", "mode", "protocol"}]:
+                           published 0 asks for a dynamic port, mode is
+                           "ingress" or "host"; every service of the
+                           kind publishes a fixed port as given
+
+Set-up then creates the networks after the manager starts and before the
+fleet is seeded (the `networks` phase), waits until each carries its subnet,
+and, after seeding, until every seeded node holds its ingress attachment.
+After the window the networks, node attachments, service endpoints and task
+attachments are read back too, and `check.check_network` adds its numbers.
 """
 from __future__ import annotations
 
@@ -39,8 +63,6 @@ from gen.traffic import Client  # noqa: E402
 TRACE_CAPACITY = 1 << 21           # program spans kept in a traced window
 BLOCK = 180                        # services per permuted block
 CHECK_WAIT_S = 60.0                # how long past the close the check waits
-# the program's own failure counter is held to 0 beside the reference check
-LIMITS = dict(check.LIMITS, tick_failures=0)
 
 
 class BenchError(RuntimeError):
@@ -178,6 +200,65 @@ def start_manager(state_dir: str, cfg: dict):
     raise BenchError("could not drain the manager's own node")
 
 
+def declares_network(cfg: dict) -> bool:
+    """Whether the configuration gives its services networks, an endpoint
+    mode or ports: then the check's network numbers are computed."""
+    return bool(cfg.get("networks")) or any(
+        key in k for k in cfg["service_kinds"]
+        for key in ("networks", "endpoint_mode", "ports"))
+
+
+def wait_store(store, done, what: str, timeout: float = 60.0) -> None:
+    """Poll a read of the store until done(tx) holds (set-up only)."""
+    deadline = time.monotonic() + timeout
+    while not store.view(done):
+        if time.monotonic() > deadline:
+            raise BenchError(f"{what} within {timeout} s")
+        time.sleep(0.01)
+
+
+def create_networks(control, store, declared: list) -> dict:
+    """Create the configuration's networks, the declared ingress in place of
+    the default one; returns name -> id once each carries its subnet."""
+    from swarmkit_tpu.api.specs import Annotations, NetworkSpec
+
+    ordered = sorted(declared, key=lambda n: not n.get("ingress"))
+    if ordered and ordered[0].get("ingress"):
+        for n in control.list_networks():
+            if n.spec.ingress:
+                control.remove_network(n.id)
+    ids = {}
+    for n in ordered:
+        ids[n["name"]] = control.create_network(NetworkSpec(
+            annotations=Annotations(name=n["name"]),
+            ingress=bool(n.get("ingress")),
+            ipam={"subnet": n["subnet"]})).id
+
+    def allocated(tx):
+        got = [tx.get_network(i) for i in ids.values()]
+        return all(g is not None and (g.driver_state or {}).get("subnet")
+                   for g in got)
+
+    wait_store(store, allocated, "the allocator gave no subnet to every "
+               "declared network")
+    return ids
+
+
+def wait_ingress_attachments(store, node_ids: list, ingress_id: str) -> None:
+    """Every seeded node holds its attachment on the ingress network."""
+    def attached(tx):
+        for nid in node_ids:
+            n = tx.get_node(nid)
+            if n is None or not any(
+                    isinstance(a, dict) and a.get("network_id") == ingress_id
+                    for a in n.attachments or ()):
+                return False
+        return True
+
+    wait_store(store, attached, "the seeded nodes got no ingress attachment",
+               timeout=120.0)
+
+
 def seed_store(store, nodes: list) -> None:
     def make(n):
         def run(tx):
@@ -228,6 +309,39 @@ def plain_nodes(store, fleet: dict) -> dict:
     return out
 
 
+def plain_network_state(store, task_objs: list, cfg: dict) -> dict:
+    """Networks, node attachments, service endpoints and task attachments
+    as plain values (check.check_network's `net`)."""
+    def atts(objs) -> list:
+        return [(a["network_id"], addr) for a in objs or ()
+                if isinstance(a, dict) for addr in a.get("addresses", ())]
+
+    def ports(ep) -> list:
+        return [tuple(p) for p in (ep or {}).get("ports") or ()]
+
+    networks, nodes, services = store.view(lambda tx: (
+        tx.find_networks(), tx.find_nodes(), tx.find_services()))
+    return {
+        "networks": {n.id: {"name": n.spec.annotations.name,
+                            "subnet": (n.driver_state or {}).get("subnet"),
+                            "ingress": bool(n.spec.ingress)}
+                     for n in networks},
+        "declared": {n["name"]: n["subnet"]
+                     for n in cfg.get("networks", ())},
+        "nodes": {n.id: atts(n.attachments) for n in nodes},
+        "services": {s.id: {"virtual_ips": [
+                         tuple(v) for v in (s.endpoint or {}).get(
+                             "virtual_ips") or ()],
+                         "ports": ports(s.endpoint)} for s in services},
+        "tasks": {t.id: {"attachments": atts(t.networks),
+                         "ports": [(p.protocol, p.target_port,
+                                    p.published_port, p.publish_mode)
+                                   for p in (t.endpoint.ports
+                                             if t.endpoint else ())]}
+                  for t in task_objs},
+    }
+
+
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              t_process: float, require_tpu: bool = True,
              control=None, definition: dict | None = None) -> dict:
@@ -264,6 +378,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         store = m.store
         phases["manager"] = time.perf_counter() - t
 
+        network_ids = {}
+        if cfg.get("networks"):
+            t = time.perf_counter()
+            network_ids = create_networks(m.control_api, store,
+                                          cfg["networks"])
+            phases["networks"] = time.perf_counter() - t
+
         t = time.perf_counter()
         fleet = fleet_gen.plan(cfg)
         nodes = fleet_gen.build_nodes(fleet)
@@ -271,6 +392,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
         t = time.perf_counter()
         seed_store(store, nodes)
+        ingress = [n["name"] for n in cfg.get("networks", ())
+                   if n.get("ingress")]
+        if ingress:
+            wait_ingress_attachments(store, fleet["ids"],
+                                     network_ids[ingress[0]])
         phases["seed"] = time.perf_counter() - t
 
         # warm-up: the cell's own traffic, from the same client the window
@@ -279,7 +405,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         col = Collector(store)
         sched = scheduler_of(node)
         client = Client(m.control_api, col, cfg, traffic, seed, "svc-",
-                        BLOCK)
+                        BLOCK, network_ids)
         client.start()
         time.sleep(float(traffic["warmup_s"]))
         if client.error is not None:
@@ -346,18 +472,35 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         with col.cond:
             counted = {tid: col.node[tid] for tid in col.assigned
                        if col.service_of.get(tid) in due_ids}
+        task_objs = store.view(lambda tx: tx.find_tasks())
         tasks = [(t.id, t.service_id, t.node_id, int(t.status.state))
-                 for t in store.view(lambda tx: tx.find_tasks())]
+                 for t in task_objs]
         nodes_plain = plain_nodes(store, fleet)
-        numbers = check.check(nodes_plain, services, tasks, counted, due_ids)
+        net = None
+        if declares_network(cfg):
+            net = plain_network_state(store, task_objs, cfg)
+
+        def judge(placed: list) -> dict:
+            out = check.check(nodes_plain, services, placed, counted,
+                              due_ids)
+            if net is not None:
+                out.update(check.check_network(services, placed, due_ids,
+                                               net))
+            return out
+
+        numbers = judge(tasks)
         control_numbers = None
         if control is not None:
-            control_numbers = check.check(
-                nodes_plain, services,
-                control(nodes_plain, services, tasks, due_ids, seed),
-                counted, due_ids)
+            control_numbers = judge(
+                control(nodes_plain, services, tasks, due_ids, seed))
+        # the program's own failure counter is held to 0 beside the
+        # reference check
+        limits = dict(check.LIMITS, tick_failures=0)
+        if net is not None:
+            limits.update(check.NET_LIMITS)
         final = counters(node, sched)
         numbers["tick_failures"] = final["tick_failures"]
+        room = check.room(nodes_plain, services, tasks)
 
         ctx = types.SimpleNamespace(
             cell=cell, config=cfg, traffic=traffic, seconds=float(seconds),
@@ -387,6 +530,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             f" full_uploads={after['full_uploads'] - before['full_uploads']}")
         say(f"due: services={len(due)} replicas={due_replicas} "
             f"not_assigned={failed} wait_s={CHECK_WAIT_S}")
+        say(f"room: reserved_cpu={room['cpu'][0]}/{room['cpu'][1]}"
+            f" reserved_mem={room['mem'][0]}/{room['mem'][1]}")
 
         metrics = {}
         for mdef in (d["per_layer"] if trace else d["end_to_end"]):
@@ -394,7 +539,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             if value is not None:
                 metrics[mdef["name"]] = {"value": value, "unit": mdef["unit"]}
         result = {
-            "correct": all(numbers[k] <= LIMITS[k] for k in LIMITS),
+            "correct": all(numbers[k] <= lim for k, lim in limits.items()),
             "attempted": due_replicas,
             "failed": failed,
             "metrics": metrics,
@@ -410,7 +555,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         if control_numbers is not None:
             result["control"] = control_numbers
             result["control_correct"] = check.verdict(control_numbers)
-        result["checks"] = {k: {"value": v, "limit": LIMITS[k]}
+        result["checks"] = {k: {"value": v, "limit": limits[k]}
                             for k, v in numbers.items()}
         return result
     finally:
